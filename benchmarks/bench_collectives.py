@@ -485,6 +485,7 @@ def main() -> list[tuple[str, float, str]]:
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(repo, "src") + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_PLATFORMS"] = "cpu"  # virtual CPU devices; never the chip
     t0 = time.perf_counter()
     proc = subprocess.run(
         [sys.executable, "-c", _SNIPPET], capture_output=True, text=True,

@@ -1,10 +1,1 @@
-"""Torrent distributed-DMA reproduction (jax).
-
-Importing any ``repro`` module first installs the jax compatibility
-shims (see :mod:`repro._jax_compat`) so the codebase's current-jax API
-surface works on the older jax baked into the offline container.
-"""
-
-from . import _jax_compat
-
-_jax_compat.install()
+"""Torrent distributed-DMA reproduction (jax)."""

@@ -192,7 +192,13 @@ class _AddrCtx:
     def member(self, flat_idx):
         """Device id of ring ``flat_idx // S``, position ``% S``."""
         self._build()
-        return flat_idx if self._flat is None else self._flat[flat_idx]
+        if self._flat is None:
+            return flat_idx
+        # A one-hot select, not a gather: XLA:CPU turns a small gather
+        # inside a scan body into a fused dynamic-slice whose generated
+        # name can collide with the scan's own slice and fail to codegen.
+        hit = flat_idx[..., None] == jnp.arange(self._flat.shape[0])
+        return jnp.sum(jnp.where(hit, self._flat, 0), axis=-1)
 
     def mask_row(self, row):
         """-1 out the row on devices outside every ring group."""
@@ -665,12 +671,16 @@ def execute_program(
         lead = x.shape[0]
         pad = (-lead) % S
         xp = jnp.pad(x, [(0, pad)] + [(0, 0)] * (x.ndim - 1)) if pad else x
-        shards = xp.reshape((S, xp.shape[0] // S) + x.shape[1:])
-        out = _run_stepped(shards, axis_name, prog)
-        if prog.out_slots == 1:  # rotation: whole payload in one slot
-            full = out[0]
-        else:
-            full = out.reshape((out.shape[0] * out.shape[1],) + x.shape[1:])
+        m = xp.shape[0] // S
+        # A flat shard is folded into 128-lane rows: the TPU compiler
+        # spends ~10 s per 32M elements slicing rows of an (S, m) array,
+        # and a fraction of a second on (S, m // 128, 128). Shards are
+        # not padded to 128 lanes: that would put bytes on the wire
+        # that program_wire_bytes (pinned exactly to the HLO) does not
+        # count.
+        row = (m // 128, 128) if x.ndim == 1 and m % 128 == 0 else (m,) + x.shape[1:]
+        out = _run_stepped(xp.reshape((S,) + row), axis_name, prog)
+        full = out.reshape((-1,) + x.shape[1:])
         return full[:lead] if pad else full
     raise ValueError(f"unknown collective {c!r}")
 

@@ -4,14 +4,12 @@ from __future__ import annotations
 
 import jax
 
+from repro.kernels import default_interpret
+
 from .kernel import flash_attention_pallas
 from .ref import attention_ref
 
 __all__ = ["flash_attention", "attention_ref"]
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def flash_attention(
@@ -28,10 +26,11 @@ def flash_attention(
 ) -> jax.Array:
     """Blockwise attention, (B, H, S, D) x (B, Hkv, S, D)^2 -> (B, H, S, D).
 
-    Pallas kernel on TPU; ``interpret=True`` (Python emulation) on CPU.
+    Pallas kernel on TPU; ``interpret=True`` (Python emulation) on CPU;
+    any other backend raises.
     """
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = default_interpret()
     return flash_attention_pallas(
         q, k, v,
         causal=causal, window=window, scale=scale,

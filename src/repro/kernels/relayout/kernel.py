@@ -9,8 +9,13 @@ block widths, padded up to MXU/VPU-friendly multiples — in VMEM,
 re-tiles it with registers only (transpose/reshape), and writes it back
 in the destination blocking.
 
-VMEM budget: one super-tile in, one out. With the default 256×256 f32
-super-tile that is 2 × 256 KiB, well inside the ~16 MiB/core VMEM.
+VMEM budget: Pallas double-buffers one super-tile in and one out, and
+each block's last two dims pad to the (sublane, 128-lane) tile. The
+super-tile grows only while the padded in + out tile stays inside
+``_VMEM_TILE_BYTES``. When both blockings share a block width (the
+paged-KV relayout ``(1, F) -> (page, F)`` regroups rows and leaves
+columns alone) a block's lanes are tiled too, in multiples of 128 that
+divide the width, so a wide block never has to fit VMEM whole.
 """
 
 from __future__ import annotations
@@ -22,19 +27,50 @@ import jax
 from jax.experimental import pallas as pl
 
 
+# Padded bytes of one super-tile in + out; double-buffered, that stays
+# well inside the 16 MiB of VMEM a TPU core gives a kernel by default.
+_VMEM_TILE_BYTES = 2 << 20
+
+
+def _padded_bytes(block: tuple[int, ...], itemsize: int) -> int:
+    """VMEM bytes of a block whose last two dims pad to the dtype's
+    (sublane, 128-lane) tile."""
+    *lead, rows, cols = block
+    sub = 8 * max(1, 4 // itemsize)
+    return (math.prod(lead) * -(-rows // sub) * sub * -(-cols // 128) * 128
+            * itemsize)
+
+
 def _supertile(src_block: tuple[int, int], dst_block: tuple[int, int],
-               shape: tuple[int, int], target: int = 256) -> tuple[int, int]:
-    """Smallest VMEM super-tile compatible with both blockings, scaled
-    up toward ``target`` (sublane/lane-aligned) when it divides shape."""
-    lm = math.lcm(src_block[0], dst_block[0])
-    ln = math.lcm(src_block[1], dst_block[1])
+               shape: tuple[int, int], itemsize: int,
+               target: int = 256) -> tuple[int, int, int]:
+    """``(tm, tn, lane)``: rows and columns of one VMEM super-tile and
+    the lane width of its blocks (the block width, unless lanes are
+    tiled). The smallest tile compatible with both blockings is scaled
+    up toward ``target`` while it divides ``shape`` and its padded
+    in + out bytes fit ``_VMEM_TILE_BYTES``."""
+    sbm, sbn = src_block
+    dbm, dbn = dst_block
     M, N = shape
-    tm, tn = lm, ln
-    while tm * 2 <= min(target, M) and M % (tm * 2) == 0:
+
+    def cost(tm, tn, lane):
+        ws, wd = (lane, lane) if lane < sbn else (sbn, dbn)
+        return (_padded_bytes((tm // sbm, tn // ws, sbm, ws), itemsize)
+                + _padded_bytes((tm // dbm, tn // wd, dbm, wd), itemsize))
+
+    tm, tn, lane = math.lcm(sbm, dbm), math.lcm(sbn, dbn), sbn
+    if sbn == dbn and cost(tm, tn, lane) > _VMEM_TILE_BYTES:
+        widths = [w for w in range(128, sbn, 128) if sbn % w == 0]
+        if widths:
+            fits = [w for w in widths if cost(tm, w, w) <= _VMEM_TILE_BYTES]
+            lane = tn = max(fits) if fits else widths[0]
+    while (tm * 2 <= min(target, M) and M % (tm * 2) == 0
+           and cost(tm * 2, tn, lane) <= _VMEM_TILE_BYTES):
         tm *= 2
-    while tn * 2 <= min(target, N) and N % (tn * 2) == 0:
+    while (lane == sbn and tn * 2 <= min(target, N) and N % (tn * 2) == 0
+           and cost(tm, tn * 2, lane) <= _VMEM_TILE_BYTES):
         tn *= 2
-    return tm, tn
+    return tm, tn, lane
 
 
 def _relayout_kernel(x_ref, o_ref, *, tm: int, tn: int,
@@ -68,25 +104,28 @@ def relayout_pallas(
     dbm, dbn = dst_block
     if (M % sbm, N % sbn, M % dbm, N % dbn) != (0, 0, 0, 0):
         raise ValueError(f"blocks {src_block}/{dst_block} must divide {shape}")
-    tm, tn = _supertile(src_block, dst_block, shape)
-    grid = (M // tm, N // tn)
+    tm, tn, lane = _supertile(src_block, dst_block, shape, x.dtype.itemsize)
+    # lane < sbn: each block's columns split into sbn // lane lane tiles
+    ws, wd = (lane, lane) if lane < sbn else (sbn, dbn)
+    grid = (M // tm, N // max(tn, sbn), sbn // ws)
     out_shape = jax.ShapeDtypeStruct((M // dbm, N // dbn, dbm, dbn), x.dtype)
     kernel = functools.partial(
-        _relayout_kernel, tm=tm, tn=tn, src_block=src_block, dst_block=dst_block
+        _relayout_kernel, tm=tm, tn=tn, src_block=(sbm, ws), dst_block=(dbm, wd)
     )
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec(
-                (tm // sbm, tn // sbn, sbm, sbn),
-                lambda i, j: (i, j, 0, 0),
+                (tm // sbm, max(tn, sbn) // sbn, sbm, ws),
+                lambda i, j, c: (i, j, 0, c),
             )
         ],
         out_specs=pl.BlockSpec(
-            (tm // dbm, tn // dbn, dbm, dbn),
-            lambda i, j: (i, j, 0, 0),
+            (tm // dbm, max(tn, dbn) // dbn, dbm, wd),
+            lambda i, j, c: (i, j, 0, c),
         ),
         out_shape=out_shape,
+        name="relayout",
         interpret=interpret,
     )(x)

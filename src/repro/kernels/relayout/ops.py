@@ -1,13 +1,15 @@
 """Public jit'd entry points for the relayout kernel.
 
-On CPU (this container) the Pallas kernel runs in ``interpret=True``;
-on TPU it compiles to Mosaic. ``relayout`` auto-selects; benchmarks and
-tests can force either path.
+On CPU the Pallas kernel runs in ``interpret=True``; on TPU it compiles
+to Mosaic; any other backend raises. ``relayout`` auto-selects;
+benchmarks and tests can force either path.
 """
 
 from __future__ import annotations
 
 import jax
+
+from repro.kernels import default_interpret
 
 from .kernel import relayout_pallas
 from .ref import dense_to_blocked, blocked_to_dense, parse_layout, relayout_ref
@@ -22,10 +24,6 @@ __all__ = [
 ]
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def relayout(
     x: jax.Array,
     shape: tuple[int, int],
@@ -36,7 +34,7 @@ def relayout(
 ) -> jax.Array:
     """Blocked-layout transform (see :mod:`.kernel`)."""
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = default_interpret()
     return relayout_pallas(x, shape, src_block, dst_block, interpret=interpret)
 
 

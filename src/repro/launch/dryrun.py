@@ -331,7 +331,9 @@ def _run_subprocess(arch: str, shape: str, mesh_kind: str, args) -> int:
         cmd.append("--overlap")
     print("::", " ".join(cmd[3:]), flush=True)
     try:
-        r = subprocess.run(cmd, timeout=args.timeout)
+        # the dry-run compiles for virtual CPU devices; never the chip
+        env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+        r = subprocess.run(cmd, timeout=args.timeout, env=env)
         return r.returncode
     except subprocess.TimeoutExpired:
         print(f"TIMEOUT: {arch} {shape} {mesh_kind}")

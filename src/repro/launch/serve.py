@@ -36,6 +36,8 @@ instead of rebuilding it.
 
     PYTHONPATH=src python -m repro.launch.serve --arch yi-6b --smoke \
         --requests 16 --max-new 32
+
+``--full`` serves the published config at full width instead.
 """
 
 from __future__ import annotations
@@ -55,6 +57,7 @@ from repro.core.chaintask import MultiChainTask
 from repro.core.program import plan_broadcast, program_wire_bytes
 from repro.core.simulator import program_latency, unicast_latency
 from repro.core.topology import MeshTopology
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.paged_kv import (
     PrefixCache,
     PrefixEntry,
@@ -148,6 +151,7 @@ class Server:
         under the old weights); re-broadcasting unchanged weights —
         e.g. the refresh at ``run()`` start — keeps entries valid.
         ``prefix_invalidated`` in the record counts what was dropped."""
+        t0 = time.perf_counter()
         invalidated = 0
         if new_params is not None:
             self.params = new_params
@@ -158,38 +162,44 @@ class Server:
                 "bytes": 0, "delivered_bytes": 0, "chunks": 0,
                 "replicas": 1, "cycles": 0, "speedup_vs_unicast": 1.0,
                 "noop": True, "prefix_invalidated": invalidated,
+                "wall_s": time.perf_counter() - t0,
             }
             self.last_delivery = {}
             self.multicast_log.append(rec)
             return rec
         flat, _ = jax.tree_util.tree_flatten(self.params)
-        true_nbytes = sum(int(np.asarray(x).nbytes) for x in flat)
+        true_nbytes = sum(int(x.nbytes) for x in flat)  # no host copy
+        # A full-width model's weights are GBs: the byte stream and each
+        # replica's copy are filled in place, one leaf or chunk at a
+        # time, and the previous delivery is dropped first.
+        self.last_delivery = {}
         # dtype-agnostic byte stream: the wire moves bytes, not floats
-        payload = (
-            np.concatenate(
-                [np.ascontiguousarray(x).reshape(-1).view(np.uint8) for x in flat]
-            )
-            if flat
-            else np.zeros(0, np.uint8)
-        )
+        payload = np.empty(true_nbytes, np.uint8)
+        off = 0
+        for x in flat:
+            leaf = np.ascontiguousarray(x).reshape(-1).view(np.uint8)
+            payload[off : off + leaf.size] = leaf
+            off += leaf.size
         cycles = unicast = chunks = 0
-        parts: dict[int, list[np.ndarray]] = {d: [] for d in dests}
-        for off in range(0, payload.size, max(1, int(chunk_bytes))):
-            chunk = payload[off : off + max(1, int(chunk_bytes))]
+        delivered = {d: np.empty(payload.size, np.uint8) for d in dests}
+        step = max(1, int(chunk_bytes))
+        for off in range(0, payload.size, step):
+            chunk = payload[off : off + step]
             task = MultiChainTask(
                 self.topo, 0, dests, chunk,
                 chains=[list(c) for c in self.plan.chains],
             )
             bufs = task.run()
             for d, buf in bufs.items():
-                parts[d].append(buf)
+                if buf.size != chunk.size:
+                    raise AssertionError(
+                        f"replica {d} got {buf.size} B of a {chunk.size} B chunk"
+                    )
+                delivered[d][off : off + chunk.size] = buf
             cycles += task.cycle_ledger["total"]
             unicast += task.unicast_cycles()
             chunks += 1
-        self.last_delivery = {
-            d: np.concatenate(p) if p else np.zeros(0, np.uint8)
-            for d, p in parts.items()
-        }
+        self.last_delivery = delivered
         rec = {
             "bytes": int(payload.nbytes),
             "delivered_bytes": sum(
@@ -200,6 +210,7 @@ class Server:
             "cycles": cycles,
             "speedup_vs_unicast": unicast / cycles if cycles else 1.0,
             "prefix_invalidated": invalidated,
+            "wall_s": time.perf_counter() - t0,  # host clock
         }
         if rec["bytes"] != true_nbytes:
             raise AssertionError(
@@ -248,6 +259,7 @@ class Server:
     def _broadcast_kv(self, entry: PrefixEntry) -> dict:
         """Chain the dense KV rows to the surviving replicas and paged-
         relayout them on each receiver."""
+        t0 = time.perf_counter()
         dests = self.plan.survivors
         payload = np.ascontiguousarray(entry.dense).reshape(-1).view(np.uint8)
         nbytes = int(payload.nbytes)
@@ -258,6 +270,7 @@ class Server:
                 "delivered_bytes": 0, "wire_bytes": 0, "replicas": 1,
                 "cycles": 0, "modeled_cycles": 0,
                 "speedup_vs_unicast": 1.0, "noop": True,
+                "wall_s": time.perf_counter() - t0,
             }
         chains = tuple(tuple(c) for c in self.plan.chains)
         program = plan_broadcast(self.topo.num_nodes, 0, chains)
@@ -311,6 +324,7 @@ class Server:
             "speedup_vs_unicast": (
                 uni_cc / modeled_cc if modeled_cc else 1.0
             ),
+            "wall_s": time.perf_counter() - t0,  # host clock
         }
 
     # -- elastic scale-down: re-form the live plan, never rebuild it -----
@@ -483,7 +497,10 @@ class Server:
 def main(argv=None) -> dict:
     p = argparse.ArgumentParser()
     p.add_argument("--arch", default="yi-6b", choices=C.ARCHS)
-    p.add_argument("--smoke", action="store_true", default=True)
+    p.add_argument("--smoke", action="store_true", default=True,
+                   help="reduced-width config (the default)")
+    p.add_argument("--full", dest="smoke", action="store_false",
+                   help="the published config at full width")
     p.add_argument("--requests", type=int, default=8)
     p.add_argument("--max-new", type=int, default=16)
     p.add_argument("--batch", type=int, default=4)
@@ -491,6 +508,7 @@ def main(argv=None) -> dict:
     args = p.parse_args(argv)
 
     logging.basicConfig(level=logging.INFO, format="%(message)s")
+    enable_compile_cache()
     sc = ServeConfig(
         arch=args.arch, smoke=args.smoke, batch=args.batch,
         prompt_len=args.prompt_len,

@@ -182,25 +182,39 @@ def make_train_step(
         )(params)
         return grads, metrics
 
+    def model_specs(params):
+        """The params' layout over the model axis (as ``param_pspecs``
+        shards them), so the Torrent reduce keeps each leaf sharded;
+        None when no model axis splits them."""
+        tp = 1 if mesh is None else dict(mesh.shape).get("model", 1)
+        if tp == 1:
+            return None
+        return jax.tree.map(
+            lambda s: _sanitize(s, mesh),
+            shd.param_pspecs(params, cfg, tp=tp),
+            is_leaf=lambda x: isinstance(x, P),
+        )
+
     def grad_fn(params, batch):
         if collectives == "torrent":
             return torrent_grad_reduce(
                 grad_fn_local, mesh, batch_specs,
                 num_chains=num_chains, algo=ar_algo,
                 wire_dtype=wire_dtype, bucket_bytes=bucket_bytes,
-                topology=topology,
+                topology=topology, param_specs=model_specs(params),
             )(params, batch)
         return grad_fn_local(params, batch)
 
     if error_feedback:
-        reduce_ef = torrent_grad_reduce(
-            grad_fn_local, mesh, batch_specs,
-            num_chains=num_chains, algo=ar_algo,
-            wire_dtype=wire_dtype, error_feedback=True,
-            bucket_bytes=bucket_bytes, topology=topology,
-        )
 
         def train_step_ef(params, opt_state, ef_state, batch):
+            reduce_ef = torrent_grad_reduce(
+                grad_fn_local, mesh, batch_specs,
+                num_chains=num_chains, algo=ar_algo,
+                wire_dtype=wire_dtype, error_feedback=True,
+                bucket_bytes=bucket_bytes, topology=topology,
+                param_specs=model_specs(params),
+            )
             grads, metrics, new_ef = reduce_ef(params, batch, ef_state)
             new_params, new_opt, om = adamw.update(
                 opt_cfg, grads, opt_state, params

@@ -28,6 +28,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro import configs as C
 from repro.checkpoint.manager import CheckpointManager
 from repro.data.pipeline import MarkovSource, Prefetcher
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.launch.steps import _named, _sanitize, make_train_step
 from repro.models import transformer as T
@@ -56,7 +57,7 @@ class TrainConfig:
     remat: str = "dots"
     loss_chunks: int = 4
     microbatches: int = 1  # gradient accumulation (HBM-fit lever)
-    ckpt_dir: str = "/tmp/repro_ckpt"
+    ckpt_dir: str | None = "/tmp/repro_ckpt"  # None: no checkpoints
     ckpt_every: int = 50
     keep_last_k: int = 3
     tp: int = 1
@@ -86,6 +87,7 @@ class Trainer:
             seed=tc.seed + 1,
         )
         self.monitor = StepMonitor()
+        self.step = 0  # steps taken so far, over every call of run()
         self._build()
 
     # -- state / step ----------------------------------------------------
@@ -167,11 +169,19 @@ class Trainer:
         }
 
     # -- driver ------------------------------------------------------------
-    def run(self) -> dict[str, Any]:
+    def run(self, steps: int | None = None) -> dict[str, Any]:
+        """Take ``steps`` more steps (default ``tc.steps``), going on
+        from where the previous call ended."""
         tc = self.tc
-        ckpt = CheckpointManager(tc.ckpt_dir, keep_last_k=tc.keep_last_k)
+        start = self.step
+        end = start + (tc.steps if steps is None else steps)
+        ckpt = (
+            CheckpointManager(tc.ckpt_dir, keep_last_k=tc.keep_last_k)
+            if tc.ckpt_dir is not None else None
+        )
         injector = FaultInjector(tc.fail_at)
         losses: list[float] = []
+        grad_norms: list[float] = []
 
         def one_step(state, i):
             injector.maybe_fail(i)
@@ -198,6 +208,7 @@ class Trainer:
                 log.info("step %5d loss %.4f lr %.2e", i, loss,
                          float(metrics["lr"]))
             losses.append(loss)
+            grad_norms.append(float(metrics["grad_norm"]))
             new_state = {"params": params, "opt": opt}
             if ef is not None:
                 new_state["ef"] = ef
@@ -207,23 +218,28 @@ class Trainer:
         state, result = resilient_loop(
             state=self.state,
             step_fn=one_step,
-            num_steps=tc.steps,
+            num_steps=end,
             ckpt=ckpt,
             ckpt_every=tc.ckpt_every,
+            start_step=start,
         )
         wall = time.time() - t0
-        ckpt.close()
+        if ckpt is not None:
+            ckpt.close()
         self.state = state
+        self.step = result.final_step
         return {
             "final_step": result.final_step,
             "restarts": result.restarts,
             "losses": losses,
+            "grad_norms": grad_norms,  # global norm of the reduced grads
             "first_loss": losses[0] if losses else None,
             "last_loss": losses[-1] if losses else None,
             "wall_s": wall,
             "straggler_events": len(self.monitor.events),
             "tokens_per_s": (
-                tc.steps * tc.global_batch * tc.seq_len / wall if wall else 0
+                (end - start) * tc.global_batch * tc.seq_len / wall
+                if wall else 0
             ),
         }
 
@@ -259,6 +275,7 @@ def main(argv=None) -> dict:
     args = p.parse_args(argv)
 
     logging.basicConfig(level=logging.INFO, format="%(message)s")
+    enable_compile_cache()
     tc = TrainConfig(
         arch=args.arch, smoke=args.smoke, steps=args.steps,
         global_batch=args.batch, seq_len=args.seq, peak_lr=args.lr,

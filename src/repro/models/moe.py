@@ -21,9 +21,9 @@ ChainProgram IR's ``plan_all_to_all``), so the MoE token exchange is
 OURS instead of a GSPMD resharding. Enabled by
 ``cfg.moe_ep_dispatch``: inside a Torrent ``shard_map`` region (e.g.
 under ``torrent_grad_reduce``) it runs directly on the manual DP axes;
-under GSPMD it opens its own nested subset ``shard_map`` over the DP
-axes when a concrete mesh is reachable (``hints.concrete_mesh``), and
-falls back to the flat path otherwise.
+under GSPMD (``jax.set_mesh``) it opens its own nested subset
+``shard_map`` over the DP axes of the context mesh, and falls back to
+the flat path otherwise.
 
 The aux load-balancing loss (switch-style E·Σ f_i·P_i) is returned to
 the caller and folded into the training loss.
@@ -374,8 +374,8 @@ def _moe_apply_ep_auto(
     * DP axes already Manual (inside a Torrent subset ``shard_map``,
       e.g. under ``torrent_grad_reduce``): call :func:`moe_apply_ep`
       directly — ``x`` is already the local token shard;
-    * DP axes Auto under GSPMD with a reachable concrete mesh: open a
-      nested subset ``shard_map`` over the DP axes around
+    * DP axes Auto under GSPMD: open a nested subset ``shard_map`` over
+      the context mesh's DP axes around
       :func:`moe_apply_ep`;
     * anything else (no mesh, no DP axes, indivisible experts/batch):
       fall back to the GSPMD-managed paths.
@@ -417,12 +417,9 @@ def _moe_apply_ep_auto(
     if any(a in manual for a in dp):
         return fallback()  # partially manual: no coherent EP axis
 
-    concrete = hints.concrete_mesh()
-    if concrete is None:
-        return fallback()
     dp_size = 1
     for a in dp:
-        dp_size *= concrete.shape[a]
+        dp_size *= mesh.shape[a]
     if cfg.num_experts % dp_size or x.shape[0] % dp_size:
         return fallback()
 
@@ -434,7 +431,7 @@ def _moe_apply_ep_auto(
     xspec = P(dp if len(dp) > 1 else dp[0], None, None)
     return jax.shard_map(
         inner,
-        mesh=concrete,
+        mesh=mesh,
         in_specs=(P(), xspec),
         out_specs=(xspec, P()),
         axis_names=set(dp),

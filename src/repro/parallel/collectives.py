@@ -506,10 +506,19 @@ def torrent_grad_reduce(
     error_feedback: bool = False,
     bucket_bytes: int | None = None,
     topology: "str | MeshTopology | None" = None,
+    param_specs: PyTree | None = None,
 ) -> Callable[..., tuple[PyTree, PyTree]]:
     """Wrap ``grad_fn(params, batch) -> (grads, metrics)`` (grads LOCAL
     to the batch shard) so grads come back chain-all-reduced over the DP
-    axes. Model-axis sharding stays automatic (subset shard_map).
+    axes. The gradients are computed in a subset shard_map (manual over
+    the DP axes, model-axis sharding automatic) and reduced in a second
+    shard_map manual over every axis.
+
+    ``param_specs`` (the params' PartitionSpecs over the non-DP axes,
+    e.g. ``model``) is the layout the reduce keeps each leaf in, so a
+    device reduces only its own slice. Without it a leaf sharded over
+    ``model`` is gathered whole before the reduce: twice the wire bytes,
+    and a full-size temporary per leaf.
 
     ``num_chains > 1`` switches each DP reduction to the multi-chain
     schedule (K concurrent sub-rings; see module docstring). It must
@@ -675,65 +684,83 @@ def torrent_grad_reduce(
             return grads_out
         return grads_out, jax.tree.unflatten(treedef, new_res)
 
+    def reduce_tree(grads, res=None):
+        """Reduce the gradient tree (and, under EF, bank the residuals):
+        returns grads, or (grads, new_residuals)."""
+        if bucket_bytes is not None:
+            return reduce_bucketed(grads, res)
+        if res is None:
+            return jax.tree.map(reduce_one, grads)
+        pairs = jax.tree.map(reduce_one, grads, res)
+        is_pair = lambda x: isinstance(x, tuple)  # noqa: E731
+        return (
+            jax.tree.map(lambda pair: pair[0], pairs, is_leaf=is_pair),
+            jax.tree.map(lambda pair: pair[1], pairs, is_leaf=is_pair),
+        )
+
+    dp_spec = dp if len(dp) > 1 else dp[0]
+    is_spec = lambda x: isinstance(x, P)  # noqa: E731
+
     def _avg_metrics(metrics):
         # metrics are per-shard means -> average over the DP group
         return jax.tree.map(
             lambda m: jax.lax.psum(m, dp) / dp_size, metrics
         )
 
+    def local_grads(params, batch):
+        """Each DP rank's gradients, stacked along a leading DP dim."""
+
+        def inner(params, batch):
+            grads, metrics = grad_fn(params, batch)
+            return jax.tree.map(lambda g: g[None], grads), _avg_metrics(metrics)
+
+        return jax.shard_map(
+            inner,
+            mesh=mesh,
+            in_specs=(jax.tree.map(lambda _: P(), params), batch_specs),
+            out_specs=(jax.tree.map(lambda _: P(dp_spec), params), P()),
+            axis_names=set(dp),
+            check_vma=False,
+        )(params, batch)
+
+    def reduce_rows(rows, res_rows=None):
+        """Reduce the stacked ``(dp, *shape)`` gradients (and bank the
+        EF residual rows) in a shard_map manual over every mesh axis.
+        Each leaf keeps its ``param_specs`` layout over the non-DP
+        axes, so every device reduces only its own slice."""
+        specs = param_specs
+        if specs is None:
+            specs = jax.tree.map(lambda g: P(*([None] * (g.ndim - 1))), rows)
+        row_specs = jax.tree.map(lambda s: P(dp_spec, *s), specs, is_leaf=is_spec)
+        first = lambda t: jax.tree.map(lambda x: x[0], t)  # noqa: E731
+
+        if res_rows is None:
+            return jax.shard_map(
+                lambda g: reduce_tree(first(g)),
+                mesh=mesh, in_specs=(row_specs,), out_specs=specs,
+                check_vma=False,
+            )(rows)
+
+        def body(g, r):
+            grads, new_r = reduce_tree(first(g), first(r))
+            return grads, jax.tree.map(lambda x: x[None], new_r)
+
+        return jax.shard_map(
+            body, mesh=mesh, in_specs=(row_specs, row_specs),
+            out_specs=(specs, row_specs), check_vma=False,
+        )(rows, res_rows)
+
     if not error_feedback:
 
         def wrapped(params, batch):
-            def inner(params, batch):
-                grads, metrics = grad_fn(params, batch)
-                if bucket_bytes is None:
-                    grads = jax.tree.map(reduce_one, grads)
-                else:
-                    grads = reduce_bucketed(grads)
-                return grads, _avg_metrics(metrics)
-
-            in_specs = (jax.tree.map(lambda _: P(), params), batch_specs)
-            out_specs = (jax.tree.map(lambda _: P(), params), P())
-            return jax.shard_map(
-                inner,
-                mesh=mesh,
-                in_specs=in_specs,
-                out_specs=out_specs,
-                axis_names=set(dp),
-                check_vma=False,
-            )(params, batch)
+            rows, metrics = local_grads(params, batch)
+            return reduce_rows(rows), metrics
 
         return wrapped
 
     def wrapped_ef(params, batch, residual):
-        def inner(params, batch, residual):
-            grads, metrics = grad_fn(params, batch)
-            # each rank's residual row: (1, *shape) -> (*shape)
-            res = jax.tree.map(lambda r: r[0], residual)
-            if bucket_bytes is None:
-                pairs = jax.tree.map(reduce_one, grads, res)
-                grads = jax.tree.map(
-                    lambda pair: pair[0], pairs,
-                    is_leaf=lambda x: isinstance(x, tuple),
-                )
-                new_res = jax.tree.map(
-                    lambda pair: pair[1][None], pairs,
-                    is_leaf=lambda x: isinstance(x, tuple),
-                )
-            else:
-                grads, new_r = reduce_bucketed(grads, res)
-                new_res = jax.tree.map(lambda r: r[None], new_r)
-            return grads, _avg_metrics(metrics), new_res
-
-        param_specs = jax.tree.map(lambda _: P(), params)
-        res_specs = ef_residual_specs(mesh, params)
-        return jax.shard_map(
-            inner,
-            mesh=mesh,
-            in_specs=(param_specs, batch_specs, res_specs),
-            out_specs=(param_specs, P(), res_specs),
-            axis_names=set(dp),
-            check_vma=False,
-        )(params, batch, residual)
+        rows, metrics = local_grads(params, batch)
+        grads, new_res = reduce_rows(rows, residual)
+        return grads, metrics, new_res
 
     return wrapped_ef

@@ -72,31 +72,6 @@ def dp_axes(axis_names) -> tuple[str, ...]:
     return tuple(a for a in BATCH if a in axis_names)
 
 
-def concrete_mesh():
-    """The concrete :class:`jax.sharding.Mesh` behind the active
-    context (``jax.set_mesh``), when recoverable — the seam model code
-    needs to open a nested subset ``shard_map`` (e.g. the Torrent MoE
-    expert-parallel dispatch). Returns ``None`` when no concrete mesh
-    is reachable, in which case callers must fall back to a
-    GSPMD-managed path."""
-    # The repo's _jax_compat shim stores the jax.set_mesh mesh on its
-    # abstract-mesh wrapper; current jax exposes no reverse lookup
-    # from AbstractMesh, so fall back to the legacy resource-env mesh
-    # (populated by `with mesh:`, which the compat set_mesh enters).
-    mesh = getattr(jax.sharding.get_abstract_mesh(), "_mesh", None)
-    if mesh is not None:
-        return mesh
-    try:
-        from jax.interpreters import pxla
-
-        env_mesh = pxla.thread_resources.env.physical_mesh
-        if env_mesh is not None and not env_mesh.empty:
-            return env_mesh
-    except Exception:
-        pass
-    return None
-
-
 def manual_axis_names() -> tuple[str, ...]:
     """Axis names currently in Manual (shard_map) mode."""
     mesh = jax.sharding.get_abstract_mesh()

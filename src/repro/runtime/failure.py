@@ -117,7 +117,7 @@ def resilient_loop(
     state: Any,  # (params, opt_state) pytree
     step_fn: Callable[[Any, int], tuple[Any, dict]],
     num_steps: int,
-    ckpt,  # CheckpointManager
+    ckpt,  # CheckpointManager, or None: no checkpoints
     ckpt_every: int = 50,
     max_restarts: int = 10,
     start_step: int = 0,
@@ -140,6 +140,9 @@ def resilient_loop(
     total loss, nothing banked downstream of nothing) falls back to
     the checkpoint-restart path. Re-forms and restarts share the
     ``max_restarts`` budget.
+
+    ``ckpt=None`` runs without checkpoints: nothing is saved, and a
+    failure that would roll back is raised instead.
     """
     if restore_fn is None:
         restore_fn = lambda s, like: ckpt.restore(s, like)
@@ -148,7 +151,8 @@ def resilient_loop(
     reforms = 0
     history: list[dict] = []
     step = start_step
-    ckpt.save(step, state, blocking=True)  # step-0 baseline
+    if ckpt is not None:
+        ckpt.save(step, state, blocking=True)  # step-0 baseline
 
     while step < num_steps:
         try:
@@ -157,7 +161,7 @@ def resilient_loop(
             history.append(metrics)
             if on_step is not None:
                 on_step(step, metrics)
-            if step % ckpt_every == 0:
+            if ckpt is not None and step % ckpt_every == 0:
                 ckpt.save(step, state)
         except SimulatedNodeFailure as e:
             nodes = getattr(e, "nodes", ()) or ()
@@ -182,6 +186,8 @@ def resilient_loop(
                     list(nodes), step,
                 )
                 continue  # state is intact: retry the same step
+            if ckpt is None:
+                raise
             restarts += 1
             if restarts + reforms > max_restarts:
                 raise RuntimeError("restart budget exhausted") from e
@@ -190,5 +196,6 @@ def resilient_loop(
             log.warning("node failure at step %d -> restoring step %s", step, latest)
             state = restore_fn(latest, state)
             step = latest
-    ckpt.save(step, state, blocking=True)
+    if ckpt is not None:
+        ckpt.save(step, state, blocking=True)
     return state, LoopResult(step, restarts, history, reforms)

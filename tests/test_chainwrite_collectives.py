@@ -124,6 +124,26 @@ def test_chain_all_reduce_non_divisible_payload(run_multidevice):
     """, timeout=900)
 
 
+def test_chain_all_reduce_scrambled_ring_divisible_payload(run_multidevice):
+    """A scrambled ring addresses ring members through a lookup inside
+    the rolled step scan. As a gather, XLA:CPU failed to codegen it at
+    some payload lengths (16 rows here) even with nothing to pad."""
+    run_multidevice("""
+    from repro.core import chainwrite as cw
+    from repro.core import chainwrite_ref as ref
+
+    mesh = jax.make_mesh((8,), ('x',), axis_types=(jax.sharding.AxisType.Auto,))
+    order = (3, 1, 0, 2, 7, 5, 6, 4)
+    xs = jnp.asarray(
+        np.random.default_rng(3).normal(size=(8, 16, 3)).astype(np.float32))
+    y = jax.jit(jax.shard_map(
+        lambda x: cw.chain_all_reduce(x[0], 'x', order)[None],
+        mesh=mesh, in_specs=P('x'), out_specs=P('x')))(xs)
+    np.testing.assert_array_equal(
+        np.asarray(y), ref.multi_all_reduce_ref(np.asarray(xs), [order]))
+    """)
+
+
 def test_order_must_be_full_permutation(run_multidevice):
     run_multidevice("""
     from repro.core import chainwrite as cw

@@ -84,3 +84,37 @@ def test_relayout_property(mi, ni, si, di):
     got = ops.relayout(x, shape, si, di)
     want = ops.relayout_ref(x, shape, si, di)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("plen", [16, 40])
+def test_paged_kv_relayout_tiles_wide_rows(plen):
+    """The paged-KV shape at h2o width, (1, F) rows -> (8, F) pages with
+    F = 30720 bf16: the super-tile splits each row into lane tiles that
+    divide F, and the result stays bit-exact."""
+    from repro.kernels.relayout.kernel import _supertile
+
+    F = 30720
+    tm, tn, lane = _supertile((1, F), (8, F), (plen, F), 2)
+    assert lane < F and F % lane == 0 and lane % 128 == 0 and tn == lane
+    dense = _rand((plen, F), jnp.bfloat16, seed=plen)
+    x = ops.dense_to_blocked(dense, (1, F))
+    got = ops.relayout(x, (plen, F), (1, F), (8, F))
+    want = ops.relayout_ref(x, (plen, F), (1, F), (8, F))
+    np.testing.assert_array_equal(
+        np.asarray(got).view(np.uint16), np.asarray(want).view(np.uint16))
+
+
+@pytest.mark.parametrize("backend,interpret", [("cpu", True), ("tpu", False)])
+def test_default_interpret_follows_backend(monkeypatch, backend, interpret):
+    from repro import kernels
+
+    monkeypatch.setattr(kernels.jax, "default_backend", lambda: backend)
+    assert kernels.default_interpret() is interpret
+
+
+def test_default_interpret_refuses_other_backends(monkeypatch):
+    from repro import kernels
+
+    monkeypatch.setattr(kernels.jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="no Pallas lowering"):
+        kernels.default_interpret()

@@ -220,3 +220,47 @@ def test_torrent_grad_reduce_matches_xla(run_multidevice):
             atol=3e-3, rtol=3e-3)
     print("torrent grad reduce OK")
     """, timeout=900)
+
+
+@pytest.mark.parametrize("bucket_bytes", [None, 4096])
+def test_torrent_grad_reduce_keeps_model_sharded_leaves(run_multidevice,
+                                                        bucket_bytes):
+    """On a (data=2, model=2) mesh each device reduces only its own slice
+    of a model-sharded gradient leaf: the values are the DP mean, and
+    the compiled step gathers nothing over ``model``. Without the
+    params' specs the reduce gathers every such leaf whole."""
+    run_multidevice(f"""
+    from jax.sharding import NamedSharding
+    from repro.parallel.collectives import torrent_grad_reduce
+
+    mesh = jax.make_mesh((2, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
+    specs = {{"w": P(None, "model"), "b": P()}}
+    params = {{"w": jnp.arange(8 * 256, dtype=jnp.float32).reshape(8, 256),
+               "b": jnp.ones((5,), jnp.float32)}}
+    params = {{k: jax.device_put(v, NamedSharding(mesh, specs[k]))
+               for k, v in params.items()}}
+    scale = jax.device_put(jnp.asarray([[1.0], [3.0]]),
+                           NamedSharding(mesh, P("data", None)))
+
+    def grad_fn(params, batch):  # rank r: params * scale[r]
+        return jax.tree.map(lambda p: p * batch[0, 0], params), {{}}
+
+    def hlo_and_grads(param_specs):
+        red = torrent_grad_reduce(grad_fn, mesh, P("data", None),
+                                  bucket_bytes={bucket_bytes},
+                                  param_specs=param_specs)
+        with jax.set_mesh(mesh):
+            fn = jax.jit(lambda p, b: red(p, b)[0])
+            return fn.lower(params, scale).compile().as_text(), fn(params, scale)
+
+    hlo, grads = hlo_and_grads(specs)
+    for k in params:  # mean of 1x and 3x
+        np.testing.assert_array_equal(np.asarray(grads[k]),
+                                      2 * np.asarray(params[k]))
+    assert grads["w"].sharding.spec == P(None, "model"), grads["w"].sharding
+    assert "all-gather" not in hlo
+    assert "collective-permute" in hlo
+    assert "all-gather" in hlo_and_grads(None)[0]
+    print("model-sharded reduce OK")
+    """, devices=4, timeout=600)
