@@ -75,6 +75,7 @@ from repro.launch.steps import (
 from repro.models import transformer as T
 from repro.parallel.collectives import MultiChainPlan
 from repro.runtime.elastic import scale_down_plan
+from repro.runtime.tracing import span
 
 log = logging.getLogger("repro.serve")
 
@@ -90,6 +91,7 @@ class Request:
     prefix_hit: bool = False  # admitted by seeding a registered prefix
     t_admit: int | None = None  # decode tick admitted to a slot
     t_done: int | None = None  # decode tick the last token was emitted
+    t_submit: float = dataclasses.field(default_factory=time.perf_counter)  # host clock
 
 
 @dataclasses.dataclass
@@ -385,33 +387,37 @@ class Server:
         r = self.slots[i]
         prompt = r.prompt
         plen = int(prompt.size)
-        entry = self.prefix_cache.lookup(prompt) if self.prefix_cache.entries else None
-        if entry is not None:
+        with span("serve.admit_request") as sp:
+            entry = self.prefix_cache.lookup(prompt) if self.prefix_cache.entries else None
             # keep at least one token to feed through decode so the
             # slot's first output falls out of the last suffix step
-            seed = entry.plen if entry.plen < plen else plen - 1
-            r.prefix_hit = True
-            if seed:
-                self.cache = seed_cache_row(self.cache, i, entry.dense, seed)
-            one_cache = jax.tree.map(lambda t: t[:, i : i + 1], self.cache)
-            tok = None
-            for p in range(seed, plen):
-                tok, one_cache = self.serve_step(
-                    self.params,
-                    jnp.asarray([int(prompt[p])], jnp.int32),
-                    jnp.int32(p),
-                    one_cache,
+            seed = 0 if entry is None else min(entry.plen, plen - 1)
+            sp.set_metadata(
+                prompt_tokens=plen, cached_tokens=seed,
+                queued_us=int((time.perf_counter() - r.t_submit) * 1e6),
+            )
+            if entry is not None:
+                r.prefix_hit = True
+                if seed:
+                    with span("serve.seed_rows"):
+                        self.cache = seed_cache_row(self.cache, i, entry.dense, seed)
+                with span("serve.suffix_decode"):
+                    one_cache = jax.tree.map(lambda t: t[:, i : i + 1], self.cache)
+                    for p in range(seed, plen):
+                        tok, one_cache = self.serve_step(
+                            self.params,
+                            jnp.asarray([int(prompt[p])], jnp.int32),
+                            jnp.int32(p),
+                            one_cache,
+                        )
+            else:
+                tok, one_cache = self.slot_prefill(
+                    self.params, jnp.asarray(prompt)[None]
                 )
             self.cache = write_cache_slot(self.cache, one_cache, i)
             first = int(np.asarray(tok)[0])
-        else:
-            first_tok, one_cache = self.slot_prefill(
-                self.params, jnp.asarray(prompt)[None]
-            )
-            self.cache = write_cache_slot(self.cache, one_cache, i)
-            first = int(np.asarray(first_tok)[0])
-        r.out.append(first)
-        self._maybe_finish(r)
+            r.out.append(first)
+            self._maybe_finish(r)
 
     def _maybe_finish(self, r: Request):
         t = r.out[-1]
@@ -438,22 +444,24 @@ class Server:
         if not active:
             return
         B = self.sc.batch
-        cur = np.zeros(B, np.int32)
-        pos = np.zeros(B, np.int32)
-        for i in active:
-            r = self.slots[i]
-            cur[i] = r.out[-1]
-            pos[i] = len(r.prompt) + len(r.out) - 1
-        toks, self.cache = self.serve_step(
-            self.params, jnp.asarray(cur), jnp.asarray(pos), self.cache
-        )
-        self.clock += 1
-        self.steps += 1
-        nxt = np.asarray(toks)
-        for i in active:
-            r = self.slots[i]
-            r.out.append(int(nxt[i]))
-            self._maybe_finish(r)
+        with span("serve.decode_step"):
+            cur = np.zeros(B, np.int32)
+            pos = np.zeros(B, np.int32)
+            for i in active:
+                r = self.slots[i]
+                cur[i] = r.out[-1]
+                pos[i] = len(r.prompt) + len(r.out) - 1
+            toks, self.cache = self.serve_step(
+                self.params, jnp.asarray(cur), jnp.asarray(pos), self.cache
+            )
+            self.clock += 1
+            self.steps += 1
+            with span("serve.step_sync"):
+                nxt = np.asarray(toks)
+            for i in active:
+                r = self.slots[i]
+                r.out.append(int(nxt[i]))
+                self._maybe_finish(r)
 
     def run(self, requests: list[Request]) -> dict[str, Any]:
         t0 = time.time()

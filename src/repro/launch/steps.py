@@ -216,9 +216,10 @@ def make_train_step(
                 param_specs=model_specs(params),
             )
             grads, metrics, new_ef = reduce_ef(params, batch, ef_state)
-            new_params, new_opt, om = adamw.update(
-                opt_cfg, grads, opt_state, params
-            )
+            with jax.named_scope("optimizer"):
+                new_params, new_opt, om = adamw.update(
+                    opt_cfg, grads, opt_state, params
+                )
             return new_params, new_opt, new_ef, {**metrics, **om}
 
         return train_step_ef
@@ -243,7 +244,8 @@ def make_train_step(
             metrics = jax.tree.map(lambda m: m.mean(0), ms)
         else:
             grads, metrics = grad_fn(params, batch)
-        new_params, new_opt, om = adamw.update(opt_cfg, grads, opt_state, params)
+        with jax.named_scope("optimizer"):
+            new_params, new_opt, om = adamw.update(opt_cfg, grads, opt_state, params)
         return new_params, new_opt, {**metrics, **om}
 
     return train_step

@@ -37,6 +37,7 @@ from repro.parallel import ef_residual_init, ef_residual_specs
 from repro.parallel import sharding as shd
 from repro.runtime.failure import FaultInjector, resilient_loop
 from repro.runtime.monitor import StepMonitor
+from repro.runtime.tracing import span
 
 log = logging.getLogger("repro.train")
 
@@ -185,20 +186,25 @@ class Trainer:
 
         def one_step(state, i):
             injector.maybe_fail(i)
-            self.monitor.start_step()
-            batch = self._device_batch(i)
-            with jax.set_mesh(self.mesh):
-                if "ef" in state:
-                    params, opt, ef, metrics = self.step_fn(
-                        state["params"], state["opt"], state["ef"], batch
-                    )
-                else:
-                    params, opt, metrics = self.step_fn(
-                        state["params"], state["opt"], batch
-                    )
-                    ef = None
-            loss = float(metrics["loss"])
-            ev = self.monitor.end_step(i)
+            # the straggler monitor times exactly the train.step span
+            with span("train.step"):
+                self.monitor.start_step()
+                with span("train.batch"):
+                    batch = self._device_batch(i)
+                with jax.set_mesh(self.mesh):
+                    if "ef" in state:
+                        params, opt, ef, metrics = self.step_fn(
+                            state["params"], state["opt"], state["ef"], batch
+                        )
+                    else:
+                        params, opt, metrics = self.step_fn(
+                            state["params"], state["opt"], batch
+                        )
+                        ef = None
+                with span("train.sync"):
+                    loss = float(metrics["loss"])
+                    grad_norm = float(metrics["grad_norm"])
+                ev = self.monitor.end_step(i)
             if ev is not None:
                 log.warning(
                     "straggler step %d: %.3fs (median %.3fs)",
@@ -208,7 +214,7 @@ class Trainer:
                 log.info("step %5d loss %.4f lr %.2e", i, loss,
                          float(metrics["lr"]))
             losses.append(loss)
-            grad_norms.append(float(metrics["grad_norm"]))
+            grad_norms.append(grad_norm)
             new_state = {"params": params, "opt": opt}
             if ef is not None:
                 new_state["ef"] = ef

@@ -92,31 +92,33 @@ def layer_apply(
 ) -> tuple[jax.Array, jax.Array]:
     """Full-sequence layer. Returns (x, aux_loss)."""
     aux = jnp.zeros((), jnp.float32)
-    h = rmsnorm(params["norm1"], x, cfg.norm_eps, bf16=cfg.bf16_norm)
-    if spec.mixer == "gqa":
-        h = attn.gqa_apply(params["mixer"], h, positions, cfg, causal=causal)
-    elif spec.mixer == "mla":
-        h = attn.mla_apply(params["mixer"], h, positions, cfg, causal=causal)
-    else:
-        h = mb.mamba2_apply(params["mixer"], h, cfg)
-    x = x + h
-    x = maybe_shard(x, BATCH, None, None)
-    if spec.cross_attention:
-        assert enc is not None
-        h = rmsnorm(params["norm_ca"], x, cfg.norm_eps, bf16=cfg.bf16_norm)
-        x = x + attn.cross_attn_apply(params["cross"], h, enc, cfg)
-    if spec.ffn != "none":
-        h = rmsnorm(params["norm2"], x, cfg.norm_eps, bf16=cfg.bf16_norm)
-        if spec.ffn == "dense":
-            h = (
-                swiglu(params["ffn"], h)
-                if cfg.ffn_activation == "swiglu"
-                else gelu_mlp(params["ffn"], h)
-            )
+    with jax.named_scope("attn"):
+        h = rmsnorm(params["norm1"], x, cfg.norm_eps, bf16=cfg.bf16_norm)
+        if spec.mixer == "gqa":
+            h = attn.gqa_apply(params["mixer"], h, positions, cfg, causal=causal)
+        elif spec.mixer == "mla":
+            h = attn.mla_apply(params["mixer"], h, positions, cfg, causal=causal)
         else:
-            h, aux = moe_mod.moe_apply(params["ffn"], h, cfg)
+            h = mb.mamba2_apply(params["mixer"], h, cfg)
         x = x + h
         x = maybe_shard(x, BATCH, None, None)
+        if spec.cross_attention:
+            assert enc is not None
+            h = rmsnorm(params["norm_ca"], x, cfg.norm_eps, bf16=cfg.bf16_norm)
+            x = x + attn.cross_attn_apply(params["cross"], h, enc, cfg)
+    if spec.ffn != "none":
+        with jax.named_scope("mlp"):
+            h = rmsnorm(params["norm2"], x, cfg.norm_eps, bf16=cfg.bf16_norm)
+            if spec.ffn == "dense":
+                h = (
+                    swiglu(params["ffn"], h)
+                    if cfg.ffn_activation == "swiglu"
+                    else gelu_mlp(params["ffn"], h)
+                )
+            else:
+                h, aux = moe_mod.moe_apply(params["ffn"], h, cfg)
+            x = x + h
+            x = maybe_shard(x, BATCH, None, None)
     return x, aux
 
 
@@ -140,29 +142,31 @@ def layer_decode(
     *,
     enc: jax.Array | None = None,
 ) -> tuple[jax.Array, Params]:
-    h = rmsnorm(params["norm1"], x, cfg.norm_eps, bf16=cfg.bf16_norm)
-    if spec.mixer == "gqa":
-        h, cache = attn.gqa_decode(params["mixer"], h, pos, cache, cfg)
-    elif spec.mixer == "mla":
-        h, cache = attn.mla_decode(params["mixer"], h, pos, cache, cfg)
-    else:
-        h, cache = mb.mamba2_decode(params["mixer"], h, cache, cfg)
-    x = x + h
-    if spec.cross_attention:
-        assert enc is not None
-        h = rmsnorm(params["norm_ca"], x, cfg.norm_eps, bf16=cfg.bf16_norm)
-        x = x + attn.cross_attn_apply(params["cross"], h, enc, cfg)
-    if spec.ffn != "none":
-        h = rmsnorm(params["norm2"], x, cfg.norm_eps, bf16=cfg.bf16_norm)
-        if spec.ffn == "dense":
-            h = (
-                swiglu(params["ffn"], h)
-                if cfg.ffn_activation == "swiglu"
-                else gelu_mlp(params["ffn"], h)
-            )
+    with jax.named_scope("attn"):
+        h = rmsnorm(params["norm1"], x, cfg.norm_eps, bf16=cfg.bf16_norm)
+        if spec.mixer == "gqa":
+            h, cache = attn.gqa_decode(params["mixer"], h, pos, cache, cfg)
+        elif spec.mixer == "mla":
+            h, cache = attn.mla_decode(params["mixer"], h, pos, cache, cfg)
         else:
-            h, _ = moe_mod.moe_apply(params["ffn"], h, cfg)
+            h, cache = mb.mamba2_decode(params["mixer"], h, cache, cfg)
         x = x + h
+        if spec.cross_attention:
+            assert enc is not None
+            h = rmsnorm(params["norm_ca"], x, cfg.norm_eps, bf16=cfg.bf16_norm)
+            x = x + attn.cross_attn_apply(params["cross"], h, enc, cfg)
+    if spec.ffn != "none":
+        with jax.named_scope("mlp"):
+            h = rmsnorm(params["norm2"], x, cfg.norm_eps, bf16=cfg.bf16_norm)
+            if spec.ffn == "dense":
+                h = (
+                    swiglu(params["ffn"], h)
+                    if cfg.ffn_activation == "swiglu"
+                    else gelu_mlp(params["ffn"], h)
+                )
+            else:
+                h, _ = moe_mod.moe_apply(params["ffn"], h, cfg)
+            x = x + h
     return x, cache
 
 
@@ -387,19 +391,20 @@ def loss_fn(
         chunks -= 1
     hs = hidden.reshape(B, chunks, S // chunks, d).swapaxes(0, 1)
     ls = labels.reshape(B, chunks, S // chunks).swapaxes(0, 1)
-    table = _head_table(params, cfg).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        table = _head_table(params, cfg).astype(jnp.float32)
 
-    def chunk_loss(carry, xs):
-        h, lbl = xs  # (B, sc, d), (B, sc)
-        logits = jnp.einsum("bsd,vd->bsv", h.astype(jnp.float32), table)
-        logits = maybe_shard(logits, BATCH, None, TP)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, lbl[..., None], axis=-1)[..., 0]
-        ce = (lse - gold).sum()
-        zl = (lse ** 2).sum() * z_loss
-        return carry + ce + zl, None
+        def chunk_loss(carry, xs):
+            h, lbl = xs  # (B, sc, d), (B, sc)
+            logits = jnp.einsum("bsd,vd->bsv", h.astype(jnp.float32), table)
+            logits = maybe_shard(logits, BATCH, None, TP)
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(logits, lbl[..., None], axis=-1)[..., 0]
+            ce = (lse - gold).sum()
+            zl = (lse ** 2).sum() * z_loss
+            return carry + ce + zl, None
 
-    total, _ = lax.scan(chunk_loss, jnp.zeros((), jnp.float32), (hs, ls))
+        total, _ = lax.scan(chunk_loss, jnp.zeros((), jnp.float32), (hs, ls))
     ntok = B * S
     loss = total / ntok + aux
     return loss, {"loss": loss, "ce": total / ntok, "aux": aux}
@@ -428,31 +433,33 @@ def layer_prefill(
     enc: jax.Array | None = None,
 ) -> tuple[jax.Array, Params]:
     """Full-sequence layer that also emits its decode cache."""
-    h = rmsnorm(params["norm1"], x, cfg.norm_eps, bf16=cfg.bf16_norm)
-    if spec.mixer == "gqa":
-        h, cache = attn.gqa_prefill(params["mixer"], h, positions, cfg, max_seq)
-    elif spec.mixer == "mla":
-        h, cache = attn.mla_prefill(params["mixer"], h, positions, cfg, max_seq)
-    else:
-        h, cache = mb.mamba2_prefill(params["mixer"], h, cfg)
-    x = x + h
-    x = maybe_shard(x, BATCH, None, None)
-    if spec.cross_attention:
-        assert enc is not None
-        h = rmsnorm(params["norm_ca"], x, cfg.norm_eps, bf16=cfg.bf16_norm)
-        x = x + attn.cross_attn_apply(params["cross"], h, enc, cfg)
-    if spec.ffn != "none":
-        h = rmsnorm(params["norm2"], x, cfg.norm_eps, bf16=cfg.bf16_norm)
-        if spec.ffn == "dense":
-            h = (
-                swiglu(params["ffn"], h)
-                if cfg.ffn_activation == "swiglu"
-                else gelu_mlp(params["ffn"], h)
-            )
+    with jax.named_scope("attn"):
+        h = rmsnorm(params["norm1"], x, cfg.norm_eps, bf16=cfg.bf16_norm)
+        if spec.mixer == "gqa":
+            h, cache = attn.gqa_prefill(params["mixer"], h, positions, cfg, max_seq)
+        elif spec.mixer == "mla":
+            h, cache = attn.mla_prefill(params["mixer"], h, positions, cfg, max_seq)
         else:
-            h, _ = moe_mod.moe_apply(params["ffn"], h, cfg)
+            h, cache = mb.mamba2_prefill(params["mixer"], h, cfg)
         x = x + h
         x = maybe_shard(x, BATCH, None, None)
+        if spec.cross_attention:
+            assert enc is not None
+            h = rmsnorm(params["norm_ca"], x, cfg.norm_eps, bf16=cfg.bf16_norm)
+            x = x + attn.cross_attn_apply(params["cross"], h, enc, cfg)
+    if spec.ffn != "none":
+        with jax.named_scope("mlp"):
+            h = rmsnorm(params["norm2"], x, cfg.norm_eps, bf16=cfg.bf16_norm)
+            if spec.ffn == "dense":
+                h = (
+                    swiglu(params["ffn"], h)
+                    if cfg.ffn_activation == "swiglu"
+                    else gelu_mlp(params["ffn"], h)
+                )
+            else:
+                h, _ = moe_mod.moe_apply(params["ffn"], h, cfg)
+            x = x + h
+            x = maybe_shard(x, BATCH, None, None)
     return x, cache
 
 
@@ -506,10 +513,11 @@ def prefill(
             caches.append(lc)
 
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps, bf16=cfg.bf16_norm)
-    logits = jnp.einsum(
-        "bd,vd->bv", x[:, -1].astype(jnp.float32),
-        _head_table(params, cfg).astype(jnp.float32),
-    )
+    with jax.named_scope("lm_head"):
+        logits = jnp.einsum(
+            "bd,vd->bv", x[:, -1].astype(jnp.float32),
+            _head_table(params, cfg).astype(jnp.float32),
+        )
     cache: dict = {"layers": caches}
     if cfg.is_encdec:
         cache["enc"] = enc.astype(jnp.bfloat16)
@@ -536,11 +544,12 @@ def decode_step(
         params["groups"], cache["layers"], cfg, x, pos, enc=enc
     )
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps, bf16=cfg.bf16_norm)
-    logits = jnp.einsum(
-        "bd,vd->bv", x[:, 0].astype(jnp.float32),
-        _head_table(params, cfg).astype(jnp.float32),
-    )
-    logits = maybe_shard(logits, BATCH, TP)
+    with jax.named_scope("lm_head"):
+        logits = jnp.einsum(
+            "bd,vd->bv", x[:, 0].astype(jnp.float32),
+            _head_table(params, cfg).astype(jnp.float32),
+        )
+        logits = maybe_shard(logits, BATCH, TP)
     new_cache = dict(cache)
     new_cache["layers"] = new_layers
     return logits, new_cache

@@ -615,11 +615,12 @@ def torrent_grad_reduce(
             )
             return _ar(x, axis, k, rings)
 
-        if hierarchical and len(dp) == 2:
-            flat = ar(flat, dp[1])  # within pod ("data")
-            flat = ar(flat, dp[0])  # across pods
-        else:
-            flat = ar(flat, dp if len(dp) > 1 else dp[0])
+        with jax.named_scope("chain"):
+            if hierarchical and len(dp) == 2:
+                flat = ar(flat, dp[1])  # within pod ("data")
+                flat = ar(flat, dp[0])  # across pods
+            else:
+                flat = ar(flat, dp if len(dp) > 1 else dp[0])
         # shards hold grads of their LOCAL mean loss; the chain sums them,
         # so divide by the DP group size to recover the global-mean grad
         # (drop-in parity with the "xla" backend).
@@ -645,8 +646,9 @@ def torrent_grad_reduce(
         payload = (
             padded[0] if len(padded) == 1 else jnp.concatenate(padded, axis=1)
         ).reshape(-1)
-        for axis, k, rings in plans:
-            payload = _ar(payload, axis, k, rings)
+        with jax.named_scope("chain"):
+            for axis, k, rings in plans:
+                payload = _ar(payload, axis, k, rings)
         mat = payload.reshape(shards, -1)
         outs, off = [], 0
         for f, m in zip(flats, widths):
@@ -754,13 +756,15 @@ def torrent_grad_reduce(
 
         def wrapped(params, batch):
             rows, metrics = local_grads(params, batch)
-            return reduce_rows(rows), metrics
+            with jax.named_scope("grad_reduce"):
+                return reduce_rows(rows), metrics
 
         return wrapped
 
     def wrapped_ef(params, batch, residual):
         rows, metrics = local_grads(params, batch)
-        grads, new_res = reduce_rows(rows, residual)
+        with jax.named_scope("grad_reduce"):
+            grads, new_res = reduce_rows(rows, residual)
         return grads, metrics, new_res
 
     return wrapped_ef
