@@ -70,6 +70,7 @@ from repro.launch.paged_kv import (
 from repro.launch.steps import (
     make_serve_step,
     make_slot_prefill_step,
+    serving_params,
     write_cache_slot,
 )
 from repro.models import transformer as T
@@ -110,18 +111,25 @@ class ServeConfig:
 
 class Server:
     """Slot-based continuous batching with greedy decode, per-slot
-    positions, and a multicast-fed prefix cache."""
+    positions, and a multicast-fed prefix cache.
+
+    ``params`` is the serving copy of the weights: every leaf that the
+    serve programs only read as bf16 is held in bf16, converted once
+    when the weights are installed (``launch.steps.serving_params``);
+    the rest stay float32. No float32 copy of a converted leaf is kept."""
 
     def __init__(self, sc: ServeConfig):
         self.sc = sc
         self.cfg = C.get_smoke_config(sc.arch) if sc.smoke else C.get_config(sc.arch)
+        # the cache first: its stacking transient then never meets the
+        # float32 weights and their bf16 copy at once
+        self.cache = T.init_cache(self.cfg, sc.batch, sc.max_seq)
         key = jax.random.PRNGKey(sc.seed)
-        self.params = T.model_init(key, self.cfg)
+        self.params = self._hold(T.model_init(key, self.cfg))
         self.slot_prefill = jax.jit(make_slot_prefill_step(self.cfg, sc.max_seq))
         self.serve_step = jax.jit(make_serve_step(self.cfg))
         self.queue: list[Request] = []
         self.slots: list[Request | None] = [None] * sc.batch
-        self.cache = T.init_cache(self.cfg, sc.batch, sc.max_seq)
         self.clock = 0  # decode ticks (the traffic harness's time base)
         self.steps = 0
         # P2MP bookkeeping (paper Fig. 4 host orchestration): ONE
@@ -137,6 +145,18 @@ class Server:
         self.prefix_cache = PrefixCache(capacity_bytes=sc.prefix_cache_bytes)
         self.kv_multicast_log: list[dict] = []
 
+    def _hold(self, params):
+        """Install ``params`` in the dtypes the serve programs read."""
+        sc = self.sc
+        with span("serve.hold_weights") as sp:
+            held = serving_params(self.cfg, params, batch=sc.batch,
+                                  prompt_len=sc.prompt_len, max_seq=sc.max_seq)
+            nbytes = {"bf16_bytes": 0, "f32_bytes": 0}
+            for x in jax.tree.leaves(held):
+                nbytes["bf16_bytes" if x.dtype == jnp.bfloat16 else "f32_bytes"] += int(x.nbytes)
+            sp.set_metadata(**nbytes)
+        return held
+
     # -- the paper's host-side P2MP: weight refresh to replicas ----------
     def broadcast_weights(self, chunk_bytes: int = 1 << 20,
                           new_params=None) -> dict:
@@ -150,13 +170,16 @@ class Server:
 
         ``new_params`` replaces the served weights before streaming and
         version-invalidates the prefix cache (cached KV was prefilled
-        under the old weights); re-broadcasting unchanged weights —
+        under the old weights). They are installed as the serving copy,
+        in the dtypes ``__init__`` holds (bf16 where the serve programs
+        only read bf16), so what streams is what the programs read and
+        the steps need no new compile. Re-broadcasting unchanged weights —
         e.g. the refresh at ``run()`` start — keeps entries valid.
         ``prefix_invalidated`` in the record counts what was dropped."""
         t0 = time.perf_counter()
         invalidated = 0
         if new_params is not None:
-            self.params = new_params
+            self.params = self._hold(new_params)
             invalidated = self.prefix_cache.on_weights_update()
         dests = self.plan.survivors
         if not dests:

@@ -21,6 +21,7 @@ from repro import configs as C
 from repro.configs.shapes import Shape, input_specs
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
+from repro.models.layers import COMPUTE_DTYPE
 from repro.optim import adamw
 from repro.parallel import sharding as shd
 from repro.parallel.collectives import torrent_grad_reduce
@@ -283,6 +284,91 @@ def make_slot_prefill_step(cfg: ModelConfig, max_seq: int, *, remat: str = "dots
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), cache
 
     return slot_prefill_step
+
+
+# Ops that only move or select a weight's elements: a leaf read through
+# them is still read as its own values.
+_SHAPE_ONLY = frozenset(
+    {"squeeze", "reshape", "slice", "dynamic_slice", "gather", "transpose", "split"}
+)
+
+
+def _sub_invar(eqn, i: int):
+    """(sub-jaxpr, invar) that operand ``i`` of a call-like ``eqn``
+    binds to, or None where the walk does not follow it (a scan carry
+    loops back into itself, so only consts and xs are followed)."""
+    name, p = eqn.primitive.name, eqn.params
+    if name == "scan":
+        if p["num_consts"] <= i < p["num_consts"] + p["num_carry"]:
+            return None
+        sub = p["jaxpr"].jaxpr
+    elif name == "jit":
+        sub = p["jaxpr"].jaxpr
+    elif name == "remat2":  # jax.checkpoint
+        sub = p["jaxpr"]
+    elif name == "closed_call":
+        sub = p["call_jaxpr"].jaxpr
+    else:
+        return None
+    return sub, sub.invars[i]
+
+
+def _read_as_compute(jaxpr, var) -> tuple[bool, bool]:
+    """(read at all, every read is a cast to ``COMPUTE_DTYPE``) for
+    ``var`` in ``jaxpr``, following shape-only ops and call operands."""
+    if any(v is var for v in jaxpr.outvars):
+        return True, False
+    read = False
+    for eqn in jaxpr.eqns:
+        for i, v in enumerate(eqn.invars):
+            if v is not var:
+                continue
+            read = True
+            name = eqn.primitive.name
+            if name == "convert_element_type":
+                ok = eqn.params["new_dtype"] == COMPUTE_DTYPE
+            elif name in _SHAPE_ONLY:
+                ok = i == 0 and all(_read_as_compute(jaxpr, o)[1] for o in eqn.outvars)
+            else:
+                sub = _sub_invar(eqn, i)
+                ok = sub is not None and _read_as_compute(*sub)[1]
+            if not ok:
+                return True, False
+    return read, True
+
+
+def serving_params(cfg: ModelConfig, params: PyTree, *, batch: int,
+                   prompt_len: int, max_seq: int) -> PyTree:
+    """``params`` in the dtypes the serve programs read them in.
+
+    Both programs (:func:`make_serve_step` on a ``batch``-slot cache and
+    :func:`make_slot_prefill_step` on a ``prompt_len`` prompt) are traced
+    on abstract values. A float32 leaf is converted once, here, to
+    ``COMPUTE_DTYPE`` when both programs read it and every read is a
+    cast to that dtype, possibly after shape-only indexing or through a
+    scan, jit or checkpoint. Every other leaf (norm scales, a head read
+    in float32, SSM decay and conv weights, a router, a table read both
+    ways) is returned as it is. Rounding once gives the bf16 values each
+    step would have made, and ``layers.cast`` of a bf16 array adds no
+    op, so the programs compute the same numbers without the casts."""
+    shapes = jax.eval_shape(lambda: params)
+    cache = jax.eval_shape(lambda: T.init_cache(cfg, batch, max_seq))
+    rows = jax.ShapeDtypeStruct((batch,), jnp.int32)
+    prompt = jax.ShapeDtypeStruct((1, prompt_len), jnp.int32)
+    programs = (
+        jax.make_jaxpr(make_serve_step(cfg))(shapes, rows, rows, cache),
+        jax.make_jaxpr(make_slot_prefill_step(cfg, max_seq))(shapes, prompt),
+    )
+
+    def cast_once(k, x):
+        return x.dtype == jnp.float32 and all(
+            all(_read_as_compute(p.jaxpr, p.jaxpr.invars[k])) for p in programs
+        )
+
+    leaves, tree = jax.tree.flatten(params)
+    return jax.tree.unflatten(tree, [
+        x.astype(COMPUTE_DTYPE) if cast_once(k, x) else x for k, x in enumerate(leaves)
+    ])
 
 
 def write_cache_slot(cache: PyTree, one_cache: PyTree, slot: int) -> PyTree:

@@ -1,8 +1,11 @@
 """Shared neural-net layers (pure functions over param pytrees).
 
 Conventions:
-* params are nested dicts of f32 arrays; compute casts to bf16
-  (``COMPUTE_DTYPE``) at the matmul boundary, norms/softmax in f32;
+* params are nested dicts of arrays, initialized in f32; compute casts
+  to bf16 (``COMPUTE_DTYPE``) at the matmul boundary, norms/softmax in
+  f32. ``cast`` of an array already in bf16 adds no op, so a serving
+  copy may hold in bf16 the leaves read only through ``cast``
+  (``launch.steps.serving_params``);
 * initializers take an explicit PRNG key;
 * all functions are shape-polymorphic over leading batch dims where
   reasonable.

@@ -109,6 +109,23 @@ def test_serve_spans_counts_and_nesting(served):
     assert [s[0] for s in spans if _inside(s, admits[1]) and s is not admits[1]] == []
 
 
+def test_hold_weights_span_counts_the_held_bytes(tmp_path):
+    built = {}
+
+    def build():
+        built["server"] = Server(ServeConfig(arch="yi-6b", smoke=True, batch=2, prompt_len=16,
+                                             max_seq=48, replicas=1))
+
+    spans = _record(tmp_path, build)
+    assert [s[0] for s in spans] == ["serve.hold_weights"]
+    held = jax.tree.leaves(built["server"].params)
+    assert spans[0][3] == {
+        "bf16_bytes": sum(int(x.nbytes) for x in held if x.dtype == jnp.bfloat16),
+        "f32_bytes": sum(int(x.nbytes) for x in held if x.dtype == jnp.float32),
+    }
+    assert spans[0][3]["bf16_bytes"] > 0 and spans[0][3]["f32_bytes"] > 0
+
+
 def test_train_spans_nest_and_count_tokens(tmp_path):
     from repro.launch.train import TrainConfig, Trainer
 
