@@ -5,18 +5,19 @@ initializes its own weights. The benchmark instead hands it the
 configuration from the cell's file and the weights it made from the
 seed: while a server or trainer is built, ``repro.configs.get_config``
 answers with the cell's configuration and ``transformer.model_init``
-with the benchmark's weights, mapped into the program's layout.
+with the benchmark's weights, mapped into the program's layout by the
+reference module that the configuration file names.
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import importlib.util
+import re
 
 import jax
 import jax.numpy as jnp
-
-from bench.reference import dense_gqa
 
 
 def model_config(config: dict):
@@ -41,36 +42,27 @@ def model_config(config: dict):
     return cfg
 
 
-def to_program_params(w: dict, cfg) -> dict:
-    """The reference layout's weights in the program's parameter tree
-    (one scanned group of identical dense layers)."""
-    groups = cfg.layer_groups()
-    if len(groups) != 1 or len(groups[0][0]) != 1:
-        raise ValueError(f"{cfg.name}: expected one group of identical layers")
-    ly = w["layers"]
-    layer = {
-        "norm1": {"scale": ly["norm1"]},
-        "mixer": {k: ly[k] for k in ("wq", "wk", "wv", "wo")},
-        "norm2": {"scale": ly["norm2"]},
-        "ffn": {k: ly[k] for k in ("gate", "up", "down")},
-    }
-    return {
-        "embed": {"table": w["embed"]},
-        "final_norm": {"scale": w["final_norm"]},
-        "groups": [[layer]],
-        "lm_head": {"table": w["head"]},
-    }
+def reference(config: dict):
+    """The reference module that the configuration file names: a path
+    under ``bench/reference/`` (the contract is that package's
+    docstring)."""
+    path = config.get("reference")
+    m = re.fullmatch(r"bench/reference/([A-Za-z][A-Za-z0-9_]*)\.py", str(path))
+    name = m and f"bench.reference.{m.group(1)}"
+    if m is None or importlib.util.find_spec(name) is None:
+        raise ValueError(f"{config['name']}: reference {path!r} is not a module under bench/reference/")
+    return importlib.import_module(name)
 
 
-def check_layout(cfg, model: dict) -> None:
+def check_layout(cfg, config: dict) -> None:
     """Raise unless the program's own parameter tree has the structure,
-    shapes and dtypes that :func:`to_program_params` produces."""
+    shapes and dtypes that the reference module's mapping produces."""
     from repro.models import transformer as T
 
+    ref = reference(config)
     theirs = jax.eval_shape(lambda: T.model_init(jax.random.PRNGKey(0), cfg))
     ours = jax.eval_shape(
-        lambda: to_program_params(dense_gqa._init(jax.random.PRNGKey(0), model), cfg)
-    )
+        lambda: ref.to_program_params(ref.init(jax.random.PRNGKey(0), config["model"]), cfg))
     if jax.tree.structure(theirs) != jax.tree.structure(ours):
         raise ValueError("the program's parameter tree changed layout")
     for a, b in zip(jax.tree.leaves(theirs), jax.tree.leaves(ours)):
@@ -87,7 +79,7 @@ def as_program(config: dict, init):
     from repro.models import transformer as T
 
     cfg = model_config(config)
-    check_layout(cfg, config["model"])
+    check_layout(cfg, config)
     arch = config["program_arch"]
     get_config, model_init = C.get_config, T.model_init
 
@@ -107,15 +99,17 @@ def as_program(config: dict, init):
 def seeded_init(config: dict, cfg, sharding=None):
     """A jitted ``key -> weights in the program's layout``; every seed
     runs the same compiled program."""
-    return jax.jit(lambda key: to_program_params(dense_gqa._init(key, config["model"]), cfg),
+    ref = reference(config)
+    return jax.jit(lambda key: ref.to_program_params(ref.init(key, config["model"]), cfg),
                    out_shardings=sharding)
 
 
 def zero_params(config: dict, cfg) -> dict:
     """Zeros in the program's layout: the same program for every seed,
     for a constructor whose own initializer runs under ``jit``."""
-    shapes = jax.eval_shape(lambda: to_program_params(
-        dense_gqa._init(jax.random.PRNGKey(0), config["model"]), cfg))
+    ref = reference(config)
+    shapes = jax.eval_shape(lambda: ref.to_program_params(
+        ref.init(jax.random.PRNGKey(0), config["model"]), cfg))
     return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes)
 
 
@@ -124,7 +118,7 @@ def build_server(config: dict, serve: dict, seed: int):
     from repro.launch.serve import ServeConfig, Server
 
     cfg = model_config(config)
-    made = seeded_init(config, cfg)(dense_gqa.seed_key(seed))
+    made = seeded_init(config, cfg)(reference(config).seed_key(seed))
     with as_program(config, lambda c: made):
         sc = ServeConfig(
             arch=config["program_arch"], smoke=False, batch=serve["slots"],

@@ -22,9 +22,8 @@ import time
 import jax
 import numpy as np
 
-from bench.lib import flops, program, traffic
+from bench.lib import program, traffic
 from bench.lib.record import Span
-from bench.reference import dense_gqa
 
 DRAIN_S = 60.0  # past the window's close, for requests that were due in it
 TRACE_S = 8.0  # the traced part of a ``--trace 1`` window: its last seconds
@@ -76,11 +75,12 @@ def _new_tokens(items: list[Tracked], t: float) -> None:
             it.times.extend([t] * n)
 
 
-def window(server, plan, model: dict, seconds: float, spans: list[Span],
+def window(server, plan, ref, model: dict, seconds: float, spans: list[Span],
            tracer=None) -> tuple[list[Tracked], float]:
     """Drive the open loop; returns the requests and the window's start
-    on the host clock."""
-    per_weight = sum(int(x.nbytes) for x in jax.tree.leaves(server.params)) / flops.param_count(model)
+    on the host clock. ``ref`` is the configuration's reference module,
+    which counts each span's work."""
+    per_weight = sum(int(x.nbytes) for x in jax.tree.leaves(server.params)) / ref.param_count(model)
     per_kv_value = jax.tree.leaves(server.cache)[0].dtype.itemsize
     t0 = time.perf_counter()
     tracked: list[Tracked] = []
@@ -115,7 +115,7 @@ def window(server, plan, model: dict, seconds: float, spans: list[Span],
             for it in admitted:
                 plen = it.req.prompt.size
                 start = min(_prefix_len(server, it), plen - 1) if it.req.prefix_hit else 0
-                work += flops.forward_flops(model, start, plen)
+                work += ref.forward_flops(model, start, plen)
             spans.append(Span("admit", s0, s1, len(admitted), work))
             _new_tokens(admitted, s1)
         if server._active():
@@ -125,8 +125,8 @@ def window(server, plan, model: dict, seconds: float, spans: list[Span],
             with jax.profiler.TraceAnnotation("bench.decode_step"):
                 server.step()
             s1 = time.perf_counter()
-            spans.append(Span("decode_step", s0, s1, len(active), flops.decode_step_flops(model, pos),
-                              flops.decode_step_bytes(model, pos, per_weight, per_kv_value)))
+            spans.append(Span("decode_step", s0, s1, len(active), ref.decode_step_flops(model, pos),
+                              ref.decode_step_bytes(model, pos, per_weight, per_kv_value)))
             _new_tokens(active, s1)
         elif i < len(plan):
             wait = plan[i].due_s - (time.perf_counter() - t0)
@@ -181,30 +181,31 @@ def sample(tracked: list[Tracked], seed: int, tokens: int, requests: int) -> lis
     return out
 
 
-def served_gaps(model: dict, seed: int, seqs, pad_to: int, read: int,
+def served_gaps(ref, model: dict, seed: int, seqs, pad_to: int, read: int,
                 quant: str | None = None) -> tuple[np.ndarray, list[np.ndarray]]:
-    """For each (prompt, served tokens): the reference's logit gap of
-    every served token. With ``quant``, also the gap of the token that
-    the lower-precision reference puts first at each position."""
-    w = dense_gqa.init_weights(model, seed)
+    """For each (prompt, served tokens): the reference module's logit
+    gap of every served token. With ``quant``, also the gap of the token
+    that the lower-precision reference puts first at each position."""
+    w = ref.init_weights(model, seed)
     served, control = [], []
     for prompt, out in seqs:
         toks = np.zeros(pad_to, np.int32)
         full = np.concatenate([prompt, out[:-1]]).astype(np.int32)
         toks[: full.size] = full
         start = prompt.size - 1
-        ref = np.asarray(dense_gqa.logits_at(w, model, toks, start, read))[: len(out)]
-        best = ref.max(-1)
-        served.append(best - ref[np.arange(len(out)), out])
+        logits = np.asarray(ref.logits_at(w, model, toks, start, read))[: len(out)]
+        best = logits.max(-1)
+        served.append(best - logits[np.arange(len(out)), out])
         if quant:
-            low = np.asarray(dense_gqa.logits_at(w, model, toks, start, read, quant))[: len(out)]
-            control.append(best - ref[np.arange(len(out)), low.argmax(-1)])
+            low = np.asarray(ref.logits_at(w, model, toks, start, read, quant))[: len(out)]
+            control.append(best - logits[np.arange(len(out)), low.argmax(-1)])
     return np.concatenate(served), control
 
 
 def run(ctx) -> dict:
     model, mix = ctx.config["model"], ctx.mix
     serve = mix["serve"]
+    ref = program.reference(ctx.config)
     ctx.phase("started")
     server = program.build_server(ctx.config, serve, ctx.seed)
     ctx.phase("weights made")
@@ -225,7 +226,7 @@ def run(ctx) -> dict:
     spans: list[Span] = []
     ctx.window_opens()
     tracer = ctx.tracer(max(0.0, ctx.seconds - TRACE_S), ctx.seconds) if ctx.trace else None
-    tracked, t0 = window(server, plan, model, ctx.seconds, spans, tracer)
+    tracked, t0 = window(server, plan, ref, model, ctx.seconds, spans, tracer)
     ctx.window_closed()
     lat = latencies(tracked, t0, ctx.seconds)
     failed = sum(not it.req.done for it in tracked)
@@ -243,7 +244,7 @@ def run(ctx) -> dict:
     del server
     gc.collect()
     t = time.perf_counter()
-    gaps, control = served_gaps(model, ctx.seed, seqs, serve["max_seq"] + mix["output"]["max"],
+    gaps, control = served_gaps(ref, model, ctx.seed, seqs, serve["max_seq"] + mix["output"]["max"],
                                 mix["output"]["max"], ctx.control) if seqs and not bad_tokens else (np.array([np.inf]), [])
     ctx.note(f"reference over {len(seqs)} requests, {gaps.size} served tokens: "
              f"{time.perf_counter() - t:.1f} s")

@@ -27,9 +27,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from bench.lib import flops, program, traffic
+from bench.lib import program, traffic
 from bench.lib.record import Span
-from bench.reference import dense_gqa
 
 CHECK_STEPS = 3
 TRACE_S = 8.0
@@ -56,7 +55,8 @@ def build(ctx):
 
 
 def initial_params(ctx, trainer, cfg):
-    return program.seeded_init(ctx.config, cfg, trainer.param_sh)(dense_gqa.seed_key(ctx.seed))
+    ref = program.reference(ctx.config)
+    return program.seeded_init(ctx.config, cfg, trainer.param_sh)(ref.seed_key(ctx.seed))
 
 
 def batches(ctx) -> traffic.TrainBatches:
@@ -101,7 +101,7 @@ def program_readings(trainer, ctx) -> dict:
 def reference_readings(ctx, opt: dict, quant: str | None = None, rows: slice | None = None) -> dict:
     """The plain reference's three steps on every chip of the cell, each
     leaf sharded along its last dimension."""
-    model = ctx.config["model"]
+    model, ref = ctx.config["model"], program.reference(ctx.config)
     n = ctx.cell["chips"]
     mesh = Mesh(np.array(jax.devices()[:n]), ("x",))
 
@@ -111,12 +111,12 @@ def reference_readings(ctx, opt: dict, quant: str | None = None, rows: slice | N
                 return NamedSharding(mesh, P(*([None] * ax + ["x"])))
         return NamedSharding(mesh, P())
 
-    shard = jax.tree.map(spec, dense_gqa.shapes(model), is_leaf=lambda s: isinstance(s, tuple))
-    w0 = jax.jit(lambda key: dense_gqa._init(key, model), out_shardings=shard)(dense_gqa.seed_key(ctx.seed))
+    shard = jax.tree.map(spec, ref.shapes(model), is_leaf=lambda s: isinstance(s, tuple))
+    w0 = jax.jit(lambda key: ref.init(key, model), out_shardings=shard)(ref.seed_key(ctx.seed))
     zeros = jax.jit(lambda w: jax.tree.map(jnp.zeros_like, w), out_shardings=shard)
     w, mu, nu = w0, zeros(w0), zeros(w0)
     data = batches(ctx)
-    fn = jax.jit(lambda w, mu, nu, t, y, step: dense_gqa.train_step(
+    fn = jax.jit(lambda w, mu, nu, t, y, step: ref.train_step(
         w, mu, nu, t, y, step, model=model, opt=opt, quant=quant),
         out_shardings=(shard, shard, shard, None, None), donate_argnums=(1, 2))
     losses, g1 = [], None
@@ -132,8 +132,8 @@ def reference_readings(ctx, opt: dict, quant: str | None = None, rows: slice | N
     change = jax.tree.map(float, _diff_norms(w, w0))
     program.free(w, mu, nu, w0)
     cfg = program.model_config(ctx.config)
-    return {"loss": losses, "grad": program.to_program_params(g1, cfg),
-            "change": program.to_program_params(change, cfg)}
+    return {"loss": losses, "grad": ref.to_program_params(g1, cfg),
+            "change": ref.to_program_params(change, cfg)}
 
 
 def norm_gap(got, ref) -> float:
@@ -159,7 +159,7 @@ def run(ctx) -> dict:
     trainer = build(ctx)
     got = program_readings(trainer, ctx)
     tokens_per_step = tr["global_batch"] * tr["seq_len"]
-    step_flops = flops.train_flops_per_token(model, tr["seq_len"]) * tokens_per_step
+    step_flops = program.reference(ctx.config).train_flops_per_token(model, tr["seq_len"]) * tokens_per_step
     spans: list[Span] = []
     ctx.window_opens()
     tracer = ctx.tracer(max(0.0, ctx.seconds - TRACE_S), ctx.seconds) if ctx.trace else None

@@ -9,10 +9,12 @@ no cache, no batching of requests and no kernels. Attention runs in
 blocks of queries so that long sequences fit.
 
 Weights live in this module's own layout (``init_weights``), made on
-the device in one jitted call from the seed. ``quant="fp8"`` computes
-every matrix product from float8 (e4m3) operands with a scale per row
-or column: the lower-precision control that the comparison has to
-reject.
+the device in one jitted call from the seed, and ``to_program_params``
+maps them onto the program's tree. ``quant="fp8"`` computes every matrix
+product from float8 (e4m3) operands with a scale per row or column: the
+lower-precision control that the comparison has to reject. The counts
+are ``bench/lib/flops.py``'s dense formulas. The contract this module
+keeps is in ``bench/reference/__init__.py``.
 """
 
 from __future__ import annotations
@@ -22,6 +24,14 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from bench.lib.flops import (  # noqa: F401  (the contract's counts)
+    decode_step_bytes,
+    decode_step_flops,
+    forward_flops,
+    param_count,
+    train_flops_per_token,
+)
 
 NEG_INF = -1e30
 Q_BLOCK = 512
@@ -59,7 +69,7 @@ def seed_key(seed: int) -> jax.Array:
     return jax.random.fold_in(k, jnp.uint32((int(seed) >> 32) & 0xFFFFFFFF))
 
 
-def _init(key: jax.Array, model: dict) -> dict:
+def init(key: jax.Array, model: dict) -> dict:
     """Embedding and head N(0, 0.02); projections N(0, fan_in^-1/2);
     norm scales 1."""
     shp = shapes(model)
@@ -87,7 +97,28 @@ def _freeze(model: dict) -> tuple:
 
 @functools.partial(jax.jit, static_argnums=1)
 def _init_jit(key, frozen):
-    return _init(key, dict(frozen))
+    return init(key, dict(frozen))
+
+
+def to_program_params(w: dict, cfg) -> dict:
+    """A tree in this module's layout in the program's parameter tree
+    (one scanned group of identical dense layers)."""
+    groups = cfg.layer_groups()
+    if len(groups) != 1 or len(groups[0][0]) != 1:
+        raise ValueError(f"{cfg.name}: expected one group of identical layers")
+    ly = w["layers"]
+    layer = {
+        "norm1": {"scale": ly["norm1"]},
+        "mixer": {k: ly[k] for k in ("wq", "wk", "wv", "wo")},
+        "norm2": {"scale": ly["norm2"]},
+        "ffn": {k: ly[k] for k in ("gate", "up", "down")},
+    }
+    return {
+        "embed": {"table": w["embed"]},
+        "final_norm": {"scale": w["final_norm"]},
+        "groups": [[layer]],
+        "lm_head": {"table": w["head"]},
+    }
 
 
 # -- arithmetic ----------------------------------------------------------------

@@ -55,7 +55,7 @@ def main(argv=None) -> int:
         mix["arrivals"]["rate_per_s"] = rate
         _, plan = traffic.schedule(mix, model["vocab_size"], args.seed, args.seconds)
         gc.collect()
-        tracked, t0 = S.window(server, plan, model, args.seconds, [])
+        tracked, t0 = S.window(server, plan, program.reference(ctx.config), model, args.seconds, [])
         lat = S.latencies(tracked, t0, args.seconds)
         third = max(1, len(tracked) // 3)
         first, last = lat["ttft_s"][:third], lat["ttft_s"][-third:]

@@ -1,5 +1,6 @@
 """The command and ``BENCHMARK.json``: the contract's shape, the refusal
-of a machine without the chip, and a cell added as data only."""
+of a machine without the chip, a cell added as data only, and a
+configuration that brings a reference module of its own."""
 
 from __future__ import annotations
 
@@ -10,6 +11,9 @@ import shutil
 import subprocess
 import sys
 
+import pytest
+
+from bench.lib import program
 from bench.tests import smoke
 
 SPEC = smoke.load("BENCHMARK.json")
@@ -75,22 +79,20 @@ def test_the_command_refuses_a_machine_without_the_chip():
     assert proc.stdout.strip() == ""
 
 
-def test_a_cell_added_as_data_only_runs(tmp_path):
-    """A new configuration, mix, limit and cell, all data, run by the
-    harness as it stands."""
-    root = tmp_path / "checkout"
+def add_cell(root, cfg: dict) -> str:
+    """Copy the benchmark into ``root`` with one more configuration
+    (``cfg``, at smoke size), a smoke-size chat mix and a cell of the
+    two, all data; returns the cell's name."""
     shutil.copytree(os.path.join(smoke.ROOT, "bench"), root / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
     spec = json.loads(json.dumps(SPEC))
-    cfg = smoke.config("h2o-danube-1.8b")
-    cfg["name"] = "h2o-danube-1.8b-tiny"
-    (root / "bench" / "configs" / "h2o-danube-1.8b-tiny.json").write_text(json.dumps(cfg))
+    (root / "bench" / "configs" / f"{cfg['name']}.json").write_text(json.dumps(cfg))
     (root / "bench" / "traffic" / "tiny-chat.json").write_text(json.dumps(smoke.mix("prefix-chat")))
-    cell = "serve.h2o-danube-1.8b-tiny.tiny-chat"
+    cell = f"serve.{cfg['name']}.tiny-chat"
     (root / "bench" / "limits" / f"{cell}.json").write_text(
         (root / "bench" / "limits" / "serve.h2o-danube-1.8b.prefix-chat.json").read_text())
     spec["configs"].append({"name": cfg["name"], "source": cfg["source"],
-                            "file": "bench/configs/h2o-danube-1.8b-tiny.json",
+                            "file": f"bench/configs/{cfg['name']}.json",
                             "reduced": cfg["reduced"], "why": "test"})
     spec["workloads"].append({"name": cell, "config": cfg["name"], "traffic": "tiny-chat",
                               "chips": 1, "why": "test"})
@@ -98,6 +100,10 @@ def test_a_cell_added_as_data_only_runs(tmp_path):
         if "serve.h2o-danube-1.8b.prefix-chat" in m.get("workloads", []):
             m["workloads"].append(cell)
     (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    return cell
+
+
+def run_added(root, cell: str) -> dict:
     code = (
         "import sys, json; sys.path[:0] = [{root!r}, {src!r}]\n"
         "from bench import run\n"
@@ -106,5 +112,59 @@ def test_a_cell_added_as_data_only_runs(tmp_path):
     proc = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True,
                           timeout=600, env=dict(os.environ, JAX_PLATFORMS="cpu"))
     assert proc.returncode == 0, proc.stderr[-3000:]
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def test_a_cell_added_as_data_only_runs(tmp_path):
+    """A new configuration, mix, limit and cell, all data, run by the
+    harness as it stands."""
+    root = tmp_path / "checkout"
+    cfg = dict(smoke.config("h2o-danube-1.8b"), name="h2o-danube-1.8b-tiny")
+    out = run_added(root, add_cell(root, cfg))
     assert out["correct"] and set(out["metrics"]) == {"setup_s", "ttft_p90_ms", "itl_p99_ms"}
+
+
+PROBE = """
+from bench.reference.dense_gqa import *  # noqa: F401,F403
+from bench.reference import dense_gqa
+
+
+def _record(name):
+    with open({record!r}, "a") as f:
+        f.write(name + "\\n")
+
+
+def logits_at(*args, **kwargs):
+    _record("logits_at")
+    return dense_gqa.logits_at(*args, **kwargs)
+
+
+def decode_step_bytes(*args, **kwargs):
+    _record("decode_step_bytes")
+    return dense_gqa.decode_step_bytes(*args, **kwargs)
+"""
+
+
+def test_a_configuration_naming_another_reference_module_runs(tmp_path):
+    """A configuration whose ``reference`` is a module of its own, added
+    as new files, is run by the harness as it stands, and the harness
+    takes its correctness and its counts from that module."""
+    root = tmp_path / "checkout"
+    record = tmp_path / "probe_calls.txt"
+    cfg = dict(smoke.config("h2o-danube-1.8b"), name="h2o-danube-1.8b-probe",
+               reference="bench/reference/probe_ref.py")
+    cell = add_cell(root, cfg)
+    (root / "bench" / "reference" / "probe_ref.py").write_text(PROBE.format(record=str(record)))
+    out = run_added(root, cell)
+    assert out["correct"], out["checks"]
+    assert set(record.read_text().split()) == {"logits_at", "decode_step_bytes"}
+
+
+@pytest.mark.parametrize("path", [
+    "bench/lib/flops.py", "bench/reference/../lib/flops.py", "/bench/reference/dense_gqa.py",
+    "bench/reference/__init__.py", "bench/reference/absent.py", None,
+])
+def test_a_reference_outside_bench_reference_is_refused(path):
+    config = {"name": "some-model", "reference": path}
+    with pytest.raises(ValueError, match="some-model"):
+        program.reference(config)
